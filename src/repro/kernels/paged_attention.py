@@ -122,7 +122,7 @@ def paged_attention_bkgd(q, k_pools, v_pools, block_tables, seq_lens, layer,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kh, G, D), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_attention",
     )(block_tables, seq_lens, layer, q, k_pools, v_pools)
 
 

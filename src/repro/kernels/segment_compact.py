@@ -71,6 +71,6 @@ def segment_compact(pool, src_idx, *, tile: int = 8192,
     out = pl.pallas_call(
         _compact_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, R, _LANES), pool.dtype),
-        interpret=interpret,
+        interpret=interpret, name="segment_compact",
     )(src_idx, rows)
     return out.reshape(M, R * _LANES)[:, :E]

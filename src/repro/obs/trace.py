@@ -13,6 +13,11 @@ args dict the caller already built.
 Timestamps come from a pluggable monotonic clock (default
 ``time.perf_counter``) shared with the engine, so queue-wait, compute
 splits and trace spans sit on one timebase.
+
+:class:`span` is the one way the engine and the pool open a span: it always
+enters a ``jax.profiler.TraceAnnotation`` named ``<cat>.<name>``, which lands
+in any running JAX profile on the same clock as the device ops, and records
+the ``B``/``E`` pair in a :class:`Tracer` only when one is given.
 """
 
 from __future__ import annotations
@@ -22,7 +27,7 @@ import time
 from collections import deque
 from contextlib import contextmanager
 
-__all__ = ["Tracer"]
+__all__ = ["Tracer", "span"]
 
 # event tuple layout: (ph, ts, tid, name, cat, args[, id])
 _PH_BEGIN = "B"
@@ -134,6 +139,47 @@ class Tracer:
             with open(path, "w") as f:
                 json.dump(doc, f)
         return doc
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+class span:
+    """``with span(name, cat, tracer, **args) as late:`` — a host span
+    named ``<cat>.<name>`` on the JAX profiler's timeline (``args`` become
+    the event's stats there) and, with ``tracer``, the same span as a
+    ``name``/``cat`` ``B``/``E`` pair on its tid 0.
+
+    ``late`` is a dict: what the body puts in it is attached to the span as
+    it closes (values known only at the end, such as a cleaning cycle's
+    victims).  With no profile running and no tracer, entering and leaving
+    costs about a microsecond.  JAX is imported at the first span, so the
+    simulator and the checkpoint store keep using :class:`Tracer` without
+    touching a device."""
+
+    __slots__ = ("name", "cat", "tracer", "args", "late", "_ann")
+
+    def __init__(self, name: str, cat: str, tracer: Tracer | None = None,
+                 **args):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation as _annotation
+        self._ann = _annotation(f"{cat}.{name}", **args)
+        self.name, self.cat, self.tracer, self.args = name, cat, tracer, args
+        self.late: dict = {}
+
+    def __enter__(self) -> dict:
+        self._ann.__enter__()
+        if self.tracer is not None:
+            self.tracer.begin(self.name, cat=self.cat, **self.args)
+        return self.late
+
+    def __exit__(self, *exc) -> None:
+        if self.late:
+            self._ann.set_metadata(**self.late)
+        if self.tracer is not None:
+            self.tracer.end(self.name, **self.late)
+        self._ann.__exit__(*exc)
 
 
 def _jsonable(v):

@@ -56,7 +56,7 @@ from ..models.layers import rmsnorm
 from .. import kernels
 from ..core.logstructure import FENCED, JournalLog, Placement
 from ..distributed.fault import TransientFault, backoff_delay
-from ..obs import DeathCalibration, MetricsLogger
+from ..obs import DeathCalibration, MetricsLogger, span
 from .kvcache import LogStructuredKVPool
 from .prefix_cache import PrefixCache
 from .scheduler import (DEFAULT_CLEAN_BUDGET, AdmissionShed,
@@ -80,6 +80,15 @@ class Request:
 def _pow2(n: int) -> int:
     """Smallest power of two ≥ n (≥ 1)."""
     return 1 << max(int(n) - 1, 0).bit_length()
+
+
+def _named(name: str, fn, **kw):
+    """``functools.partial(fn, **kw)`` under ``name``: jitted, it lowers to
+    the program ``jit_<name>``, the name the profiler's XLA Modules line
+    shows for each of its runs."""
+    p = functools.partial(fn, **kw)
+    p.__name__ = name
+    return p
 
 
 # per-head projections shard over the serving mesh; every other weight is
@@ -188,7 +197,7 @@ def make_paged_decode_step(cfg: ModelConfig, page_T: int, use_pallas: bool,
     """
     step = _build_decode_step(cfg, page_T, use_pallas, max_chunk, mesh,
                               kv_shard, rep_shard, stop_token, trash_page)
-    return jax.jit(step, donate_argnums=(1, 2, 4, 5))
+    return jax.jit(_named("decode_step", step), donate_argnums=(1, 2, 4, 5))
 
 
 def _build_decode_step(cfg, page_T, use_pallas, max_chunk, mesh, kv_shard,
@@ -323,7 +332,7 @@ def make_fused_prefill_decode_step(cfg: ModelConfig, page_T: int,
             params, k_pools, v_pools, bt, seq_lens, tokens, active, n)
         return out, first, k_pools, v_pools, seq_lens, tokens
 
-    return jax.jit(fused, donate_argnums=(1, 2, 4, 5),
+    return jax.jit(_named("fused_step", fused), donate_argnums=(1, 2, 4, 5),
                    static_argnames=("kv_len",))
 
 
@@ -580,17 +589,18 @@ class PagedServingEngine:
         # the cached prefix is the *unrounded* activation value, which is
         # what makes prefix-hit tail prefills bit-exact (DESIGN.md §7)
         self._prefill = jax.jit(
-            functools.partial(_prefill_fn, cfg=cfg, cache_dtype=pool_dtype),
+            _named("prefill", _prefill_fn, cfg=cfg, cache_dtype=pool_dtype),
             static_argnames=("max_len",))
         self._prefill_cont = jax.jit(
-            functools.partial(_prefill_cont_fn, cfg=cfg,
-                              cache_dtype=pool_dtype),
+            _named("prefill_cont", _prefill_cont_fn, cfg=cfg,
+                   cache_dtype=pool_dtype),
             static_argnames=("max_len", "kv_len"))
         self._scatter = jax.jit(
-            functools.partial(_scatter_prefill_fn, shard=self._kv_shard),
+            _named("scatter_prefill", _scatter_prefill_fn,
+                   shard=self._kv_shard),
             donate_argnums=(0, 1))
         self._move = jax.jit(
-            functools.partial(_move_pages_fn, shard=move_shard),
+            _named("move_pages", _move_pages_fn, shard=move_shard),
             donate_argnums=(0, 1), static_argnames=("use_pallas",))
         self._next_rid = 0
         # --- crash safety & chaos (DESIGN.md §10) -------------------------
@@ -742,16 +752,9 @@ class PagedServingEngine:
         (better to die than to serve unjournaled state)."""
         if self.journal is None:
             return None
-        ph = self._phase_acc
-        if ph is None:
+        with self._span("journal"):
             return self._with_retries(
                 "journal", lambda: self.journal.append_record(rec))
-        t = self.clock()
-        try:
-            return self._with_retries(
-                "journal", lambda: self.journal.append_record(rec))
-        finally:
-            ph["journal"] = ph.get("journal", 0.0) + self.clock() - t
 
     def _with_retries(self, op: str, fn):
         """Run ``fn`` with fault injection keyed by ``op`` and bounded
@@ -773,22 +776,30 @@ class PagedServingEngine:
                     time.sleep(delay)
         raise AssertionError("unreachable")
 
-    def _timed_retries(self, op: str, fn):
-        """:meth:`_with_retries` plus phase attribution: with a phase
-        accumulator active, ``op``'s wall time lands in the current
-        dispatch's split (and a trace span when a tracer is attached)."""
-        ph, tr = self._phase_acc, self.tracer
-        if ph is None:
-            return self._with_retries(op, fn)
+    def _span(self, phase: str, row: bool = True, **args):
+        """One engine phase: the span ``engine.<phase>`` on the profiler's
+        timeline (:class:`repro.obs.span`).  With phase rows on
+        (``phase_log=True`` or a tracer attached) the tracer records it
+        too, and with ``row`` its wall time adds to the current dispatch's
+        row for :meth:`phase_report` (several compactions or journal
+        appends can land in one dispatch).  Spans outside that split
+        (``step``, ``prefill``, ``emit``, and the alloc and host sync nested
+        in admission) pass ``row=False``, so a row keeps the phases it
+        had."""
+        if self._phase_acc is None:
+            return span(phase, "engine", **args)
+        return self._row_span(phase, row, args)
+
+    @contextlib.contextmanager
+    def _row_span(self, phase: str, row: bool, args: dict):
+        ph = self._phase_acc
         t = self.clock()
-        if tr is not None:
-            tr.begin(op, cat="engine")
         try:
-            return self._with_retries(op, fn)
+            with span(phase, "engine", self.tracer, **args) as late:
+                yield late
         finally:
-            if tr is not None:
-                tr.end(op)
-            ph[op] = ph.get(op, 0.0) + self.clock() - t
+            if row:
+                ph[phase] = ph.get(phase, 0.0) + self.clock() - t
 
     # ------------------------------------------------------------- requests
     def submit(self, prompt: np.ndarray, max_new_tokens: int) -> int:
@@ -1061,9 +1072,10 @@ class PagedServingEngine:
         # decref them) — otherwise every failed admission of a hitting
         # prompt would permanently inflate the shared pages' refcounts.
         try:
-            pages_new = self.pool.alloc_blocks(
-                np.full(n_pages - n_shared, req.rid, dtype=np.int64),
-                Placement(est_death=est))
+            with self._span("alloc", row=False):
+                pages_new = self.pool.alloc_blocks(
+                    np.full(n_pages - n_shared, req.rid, dtype=np.int64),
+                    Placement(est_death=est))
         except Exception:
             if n_shared:
                 self.pool.free_pages(self.bt[i, :n_shared].astype(np.int64))
@@ -1120,7 +1132,9 @@ class PagedServingEngine:
         if self.prefill_chunk:
             # chunked mode: park the slot in the *prefilling* state; step()
             # feeds one chunk per fused dispatch until _pf_complete
-            self._start_chunked(i, req, prompt, plen, n_pages, n_shared, est)
+            with self._span("prefill", row=False):
+                self._start_chunked(i, req, prompt, plen, n_pages, n_shared,
+                                    est)
             return
 
         # dense prefill -> scatter K/V into the allocated pages.  Prompt and
@@ -1131,37 +1145,43 @@ class PagedServingEngine:
         # prefill attends the cached prefix K/V gathered straight from the
         # pool pages (exact-length, so key positions align absolutely and
         # the arithmetic matches a cold prefill row-for-row).
-        if n_shared:
-            tlen = plen - n_shared * T
-            tok_bucket, max_len = self._prefill_bucket(tlen,
-                                                       n_pages - n_shared)
-            toks = np.zeros(tok_bucket, np.int32)
-            toks[:tlen] = prompt[n_shared * T:]
-            prefix_pages = self.bt[i, :n_shared].astype(np.int32)  # post-remap
-            # kv_len = the bucket a cold full prefill of this prompt would
-            # attend over: identical key extents are what make the hit
-            # arithmetic bit-identical (gqa_prefill_cont's dtype/tiling note)
-            kv_len = self._prefill_bucket(plen, n_pages)[0]
-            with self._mesh_ctx():
-                first_tok, ks, vs = self._prefill_cont(
-                    self.params, self.k_pools, self.v_pools,
-                    self._put_rep(prefix_pages), jnp.asarray(toks)[None],
-                    np.int32(tlen), max_len=max_len, kv_len=kv_len)
-        else:
-            tok_bucket, max_len = self._prefill_bucket(plen, n_pages)
-            toks = np.zeros(tok_bucket, np.int32)
-            toks[:plen] = prompt
-            with self._mesh_ctx():
-                first_tok, ks, vs = self._prefill(
-                    self.params, jnp.asarray(toks)[None], np.int32(plen),
-                    max_len=max_len)
-        nb = max_len // T
-        # scatter the whole bucket; pages beyond the allocation land in the
-        # trash page, so the compile key is the bucket size, not n_pages
-        pages_pad = np.full(nb, self.trash_page, np.int32)
-        pages_pad[:len(pages_new)] = pages_new
-        self.k_pools, self.v_pools = self._scatter(
-            self.k_pools, self.v_pools, ks, vs, self._put_rep(pages_pad))
+        with self._span("prefill", row=False):
+            if n_shared:
+                tlen = plen - n_shared * T
+                tok_bucket, max_len = self._prefill_bucket(tlen,
+                                                           n_pages - n_shared)
+                toks = np.zeros(tok_bucket, np.int32)
+                toks[:tlen] = prompt[n_shared * T:]
+                # the prefix's pages as the alloc's compaction left them
+                prefix_pages = self.bt[i, :n_shared].astype(np.int32)
+                # kv_len = the bucket a cold full prefill of this prompt
+                # would attend over: identical key extents are what make
+                # the hit arithmetic bit-identical (gqa_prefill_cont's
+                # dtype/tiling note)
+                kv_len = self._prefill_bucket(plen, n_pages)[0]
+                with self._mesh_ctx():
+                    first_tok, ks, vs = self._prefill_cont(
+                        self.params, self.k_pools, self.v_pools,
+                        self._put_rep(prefix_pages),
+                        jnp.asarray(toks)[None], np.int32(tlen),
+                        max_len=max_len, kv_len=kv_len)
+            else:
+                tok_bucket, max_len = self._prefill_bucket(plen, n_pages)
+                toks = np.zeros(tok_bucket, np.int32)
+                toks[:plen] = prompt
+                with self._mesh_ctx():
+                    first_tok, ks, vs = self._prefill(
+                        self.params, jnp.asarray(toks)[None],
+                        np.int32(plen), max_len=max_len)
+            nb = max_len // T
+            # scatter the whole bucket; pages beyond the allocation land in
+            # the trash page, so the compile key is the bucket size, not
+            # n_pages
+            pages_pad = np.full(nb, self.trash_page, np.int32)
+            pages_pad[:len(pages_new)] = pages_new
+            self.k_pools, self.v_pools = self._scatter(
+                self.k_pools, self.v_pools, ks, vs,
+                self._put_rep(pages_pad))
 
         # register this prompt's full (immutable) pages for future sharing;
         # already-cached keys keep their existing page, so a recomputed
@@ -1170,10 +1190,13 @@ class PagedServingEngine:
             self.prefix_cache.insert(prompt,
                                      self.bt[i, :plen // T].copy(), est)
 
+        # the prefill's first token: the host waits here for the device
+        with self._span("host_sync", row=False):
+            first = int(first_tok[0])
         self.rid[i] = req.rid
         self.lens[i] = plen
         self._prompt[i] = req.prompt
-        self.tokens[i] = int(first_tok[0])
+        self.tokens[i] = first
         self.to_gen[i] = req.max_new_tokens - 1
         if resume:
             # keep the carried buffer: decode re-emits the same tokens
@@ -1191,8 +1214,7 @@ class PagedServingEngine:
             # the prefill's first output token is journaled before any
             # finish record this admission could produce (cap/stop below);
             # a resume's first token was journaled by its original start
-            self._jrec({"t": "first", "rid": req.rid,
-                        "tok": int(first_tok[0])})
+            self._jrec({"t": "first", "rid": req.rid, "tok": first})
         self._bt_dirty = self._state_dirty = True
         # the prefill token may already complete the request: cap reached,
         # or (stop-token decode) the first emitted token is the stop token
@@ -1381,22 +1403,21 @@ class PagedServingEngine:
         split into attributed phases (admit / alloc / upload / dispatch /
         host_sync, plus compaction and journal time accumulated wherever
         they fire) — the latency breakdown the overload bench reports.
-        Disabled (the default), the whole apparatus is one ``None`` check."""
+        Disabled (the default), the apparatus is one ``None`` check plus
+        the ``engine.*`` profiler annotations (:meth:`_span`)."""
         tr = self.tracer
         ph = {} if (self.phase_log or tr is not None) else None
         self._phase_acc = ph
         t_step = self.clock()
-        if tr is not None:
-            tr.begin("step", cat="engine", dispatch=self.dispatches)
         try:
-            return self._step_impl(ph, tr, t_step)
+            with self._span("step", row=False, dispatch=self.dispatches):
+                return self._step_impl()
         finally:
             self._phase_acc = None
             if tr is not None:
                 tr.counter("pool", free_blocks=self.pool.free_blocks(),
                            queue_depth=len(self.queue) + len(self._resume),
                            active_slots=int((self.rid >= 0).sum()))
-                tr.end("step")
             if ph is not None and ph.pop("dispatched", False):
                 ph["total"] = self.clock() - t_step
                 self.dispatch_phases.append(ph)
@@ -1404,21 +1425,13 @@ class PagedServingEngine:
                     and self.dispatches % self.metrics_every == 0):
                 self._sample_metrics()
 
-    def _step_impl(self, ph, tr, t_step) -> list[int]:
+    def _step_impl(self) -> list[int]:
         if self.async_compaction:
             # commit last step's in-flight remaps, plan + issue new moves
             # ahead of admission — cleaning leaves the dispatch path
             self._pump_compaction()
-        if ph is None:
+        with self._span("admit"):
             self._admit()
-        else:
-            t_a = self.clock()
-            if tr is not None:
-                tr.begin("admit", cat="engine")
-            self._admit()
-            if tr is not None:
-                tr.end("admit")
-            ph["admit"] = self.clock() - t_a
         done, self._admit_done = self._admit_done, []
         active = (self.rid >= 0) & ~self._prefilling
         pf = self._pf
@@ -1426,8 +1439,8 @@ class PagedServingEngine:
             return done
         self.dispatches += 1
         t0 = self.clock()
-        if ph is not None:
-            ph["dispatched"] = True
+        if self._phase_acc is not None:
+            self._phase_acc["dispatched"] = True
 
         # pages for the incoming tokens must exist before the dispatch writes
         # them; one batched alloc covers every slot at a page boundary
@@ -1451,33 +1464,23 @@ class PagedServingEngine:
                 if not active.any() and pf is None:
                     return done
         if growing.size:
-            t_al = self.clock() if ph is not None else 0.0
-            rem = np.array([self._predict_remaining(
-                int(self._out_n[j] + self.to_gen[j]), int(self._out_n[j]))
-                for j in growing])
-            pages = self.pool.alloc_blocks(
-                self.rid[growing],
-                Placement(est_death=self.pool.u_now
-                          + (self.lens[growing] + rem).astype(np.float64)))
-            self.bt[growing, self.npages[growing]] = pages
-            self.npages[growing] += 1
-            self._bt_dirty = True
-            self._jrec({"t": "al", "r": self.rid[growing].tolist(),
-                        "pg": pages.tolist()})
-            if ph is not None:
-                ph["alloc"] = self.clock() - t_al
+            with self._span("alloc"):
+                rem = np.array([self._predict_remaining(
+                    int(self._out_n[j] + self.to_gen[j]),
+                    int(self._out_n[j])) for j in growing])
+                pages = self.pool.alloc_blocks(
+                    self.rid[growing],
+                    Placement(est_death=self.pool.u_now
+                              + (self.lens[growing] + rem).astype(np.float64)))
+                self.bt[growing, self.npages[growing]] = pages
+                self.npages[growing] += 1
+                self._bt_dirty = True
+                self._jrec({"t": "al", "r": self.rid[growing].tolist(),
+                            "pg": pages.tolist()})
 
         n = self._event_horizon(active)
-        if ph is None:
+        with self._span("upload"):
             self._sync_device()
-        else:
-            t_up = self.clock()
-            if tr is not None:
-                tr.begin("upload", cat="engine")
-            self._sync_device()
-            if tr is not None:
-                tr.end("upload")
-            ph["upload"] = self.clock() - t_up
         if pf is not None:
             # ---- fused dispatch: one prefill chunk + n decode tokens ----
             C, T = self.prefill_chunk, self.page_T
@@ -1509,29 +1512,42 @@ class PagedServingEngine:
                         self._put_rep(cpages), self._put_rep(ptoks[None]),
                         np.int32(pos), np.int32(last_idx),
                         kv_len=pf["kv_len"])
-            (out, first, self.k_pools, self.v_pools, self._lens_dev,
-             self._tok_dev) = self._timed_retries("dispatch", _dispatch_fused)
+            with self._span("dispatch"):
+                (out, first, self.k_pools, self.v_pools, self._lens_dev,
+                 self._tok_dev) = self._with_retries("dispatch",
+                                                     _dispatch_fused)
             pf["pos"] = pos + C
             # host-only progress marker (the slot is decode-masked, so the
             # stale device-side value is never consumed — no upload)
             self.lens[pi] = min(pf["pos"], pf["plen"])
             self.prefill_chunks_dispatched += 1
-            if tr is not None:
-                tr.async_instant("req.prefill_chunk", int(self.rid[pi]),
-                                 tid=1, cat="request", pos=int(pf["pos"]))
+            if self.tracer is not None:
+                self.tracer.async_instant(
+                    "req.prefill_chunk", int(self.rid[pi]), tid=1,
+                    cat="request", pos=int(pf["pos"]))
         else:
             is_last = False
-            (out, self.k_pools, self.v_pools, self._lens_dev,
-             self._tok_dev) = self._timed_retries(
-                "dispatch",
-                lambda: self._decode(self.params, self.k_pools, self.v_pools,
-                                     self._bt_dev, self._lens_dev,
-                                     self._tok_dev, self._act_dev,
-                                     np.int32(n)))
+            with self._span("dispatch"):
+                (out, self.k_pools, self.v_pools, self._lens_dev,
+                 self._tok_dev) = self._with_retries(
+                    "dispatch",
+                    lambda: self._decode(self.params, self.k_pools,
+                                         self.v_pools, self._bt_dev,
+                                         self._lens_dev, self._tok_dev,
+                                         self._act_dev, np.int32(n)))
         # ONE host sync per dispatch, not per token
-        toks = self._timed_retries("host_sync",
-                                   lambda: np.asarray(out))[:n]
+        with self._span("host_sync"):
+            toks = self._with_retries("host_sync",
+                                      lambda: np.asarray(out))[:n]
+        with self._span("emit", row=False):
+            return self._emit(toks, n, active, done, t0,
+                              first if is_last else None)
 
+    def _emit(self, toks, n, active, done, t0, first) -> list[int]:
+        """After the dispatch's host sync: copy out the emitted tokens,
+        journal them, finish and release completed slots, graduate a
+        prefill whose final chunk landed (``first``: its device buffer),
+        and update the decode-rate EWMA.  Returns the finished ids."""
         # host bookkeeping: O(active slots) per dispatch.  With stop tokens
         # a slot may have stopped mid-dispatch: it emitted tokens only up to
         # and including its first stop token (the device froze it there), so
@@ -1576,8 +1592,10 @@ class PagedServingEngine:
                 done.append(int(self.rid[i]))
                 self._finish(int(i))
 
-        if pf is not None and is_last:
-            fin = self._pf_complete(int(np.asarray(first)[0]))
+        if first is not None:
+            with self._span("host_sync"):
+                first = int(np.asarray(first)[0])
+            fin = self._pf_complete(first)
             if fin is not None:
                 done.append(fin)
 
@@ -1601,23 +1619,6 @@ class PagedServingEngine:
         return self.finished
 
     # ----------------------------------------------------------- compaction
-    @contextlib.contextmanager
-    def _compaction_phase(self, moves: int):
-        """Attribute a compaction span to the current dispatch's phase split
-        (accumulated — several plans/pumps can fire per dispatch)."""
-        ph, tr = self._phase_acc, self.tracer
-        t_c = self.clock() if ph is not None else 0.0
-        if tr is not None:
-            tr.begin("compaction", cat="engine", moves=moves)
-        try:
-            yield
-        finally:
-            if tr is not None:
-                tr.end("compaction")
-            if ph is not None:
-                ph["compaction"] = (ph.get("compaction", 0.0)
-                                    + self.clock() - t_c)
-
     def _move_plan(self, plan) -> None:
         """Journal + issue the jitted donated move for one plan.  The pool's
         accounting already committed the placement, so the tensor move
@@ -1650,7 +1651,7 @@ class PagedServingEngine:
         completion before the pool hands out any plan-freed page id."""
         if len(plan) == 0:
             return
-        with self._compaction_phase(len(plan)):
+        with self._span("compaction", moves=len(plan)):
             self._move_plan(plan)
             self._apply_remap(plan)
 
@@ -1709,7 +1710,7 @@ class PagedServingEngine:
         pool = self.pool
         if not (self._inflight_plans or pool.pending_plans):
             return
-        with self._compaction_phase(0):
+        with self._span("compaction"):
             while self._inflight_plans:
                 self._commit_plan(self._inflight_plans.pop(0))
             if not pool.pending_plans:
@@ -1748,7 +1749,7 @@ class PagedServingEngine:
         the alloc path when capacity is needed *now*."""
         if not (self._inflight_plans or self.pool.pending_plans):
             return
-        with self._compaction_phase(0):
+        with self._span("compaction"):
             while self._inflight_plans:
                 self._commit_plan(self._inflight_plans.pop(0))
             while self.pool.pending_plans:

@@ -45,11 +45,13 @@ batched and vectorized: cost is O(slabs touched), not O(blocks).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 
 import numpy as np
 
 from ..core.logstructure import FENCED, USED, FrameLog, Placement, StoreStats
+from ..obs import span
 
 NO_PAGE = -1
 
@@ -113,6 +115,25 @@ class CompactionPlan:
         src[:len(self)] = self.src_pages
         dst[:len(self)] = self.dst_pages
         return src, dst
+
+
+def _cleaning_cycle(fn):
+    """Run ``fn``, one cleaning cycle, inside a ``pool.compact`` span:
+    victim selection, evacuation accounting and survivor placement, with
+    the engine's move and remap inside it where they run synchronously.
+    The span's args are the slabs the cycle cleaned and the pages it
+    moved, read off the store's counters."""
+    @functools.wraps(fn)
+    def cycle(self, *args, **kw):
+        st = self.stats
+        v0, m0 = st.cleaned_segments, st.gc_moves
+        with span("compact", "pool", self.core.tracer) as late:
+            try:
+                return fn(self, *args, **kw)
+            finally:
+                late.update(victims=st.cleaned_segments - v0,
+                            moves=st.gc_moves - m0)
+    return cycle
 
 
 class LogStructuredKVPool:
@@ -407,6 +428,7 @@ class LogStructuredKVPool:
             return None
         return self.compact()
 
+    @_cleaning_cycle
     def compact(self):
         """Evacuate victims; returns CompactionPlan(src_pages, dst_pages).
 
@@ -449,6 +471,7 @@ class LogStructuredKVPool:
         return plan
 
     # --------------------------------------------- async two-phase cleaning
+    @_cleaning_cycle
     def plan_compaction(self, budget: int = 0) -> list:
         """Phase one of async cleaning (DESIGN.md §13): one cleaning cycle
         whose victims are *fenced* instead of freed, cut into budget-sized

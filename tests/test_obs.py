@@ -5,8 +5,10 @@ Pins the contracts the rest of the repo leans on: the tracer's bounded ring
 (valid events, B/E spans nest per (pid, tid), stable integer pid/tid), the
 engine's golden ``metrics()`` schema and its single pluggable clock, the
 ``StoreStats.wamp()`` zero-write fix with ``per_stream_wamp``, the
-MetricsLogger delta semantics, and death-prediction calibration end to end
-(core kill path → per-stream misroute rate + lifetime histograms).
+MetricsLogger delta semantics, death-prediction calibration end to end
+(core kill path → per-stream misroute rate + lifetime histograms), and the
+engine's and pool's spans on the JAX profiler's clock (names, nesting, and
+counts against the engine's counters, read from a CPU profile).
 """
 
 import io
@@ -349,3 +351,175 @@ def test_engine_obs_disabled_is_inert_and_identical(smoke_model):
     assert obs_toks == plain_toks, "observability changed decoded tokens"
     assert obs.metrics()["wamp"] == plain.metrics()["wamp"]
     assert obs.metrics()["blocks_moved"] == plain.metrics()["blocks_moved"]
+
+
+# ------------------------------------- profiler spans (jax.profiler clock)
+
+# the phases phase_report() splits a dispatch into
+REPORT_PHASES = {"admit", "alloc", "upload", "dispatch", "host_sync",
+                 "compaction", "journal"}
+
+
+def test_span_records_tracer_pair_and_late_args():
+    from repro.obs import span
+    tr = Tracer(capacity=16, clock=Tick())
+    with span("compact", "pool", tr, budget=4) as late:
+        with span("compaction", "engine", tr):
+            pass
+        late["victims"] = 2
+    with span("upload", "engine") as late:   # no tracer: profiler only
+        assert late == {}
+    _check_chrome_trace(tr.export())
+    ev = tr.events()
+    assert [(e["ph"], e["name"], e.get("cat")) for e in ev] == [
+        ("B", "compact", "pool"), ("B", "compaction", "engine"),
+        ("E", "compaction", None), ("E", "compact", None)]
+    assert ev[0]["args"] == {"budget": 4}
+    assert ev[-1]["args"] == {"victims": 2}
+
+
+def _profiled_spans(trace_dir):
+    """Every ``engine.*`` / ``pool.*`` host event of the profile in
+    ``trace_dir`` as a dict with its interval, its stats and the name of
+    the innermost such event around it on the same thread."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for pl in ProfileData.from_file(path).planes:
+        if not pl.name.startswith("/host:"):
+            continue
+        for ln in pl.lines:
+            evs = sorted(((e.start_ns, -e.duration_ns, e) for e in ln.events
+                          if e.name.startswith(("engine.", "pool."))),
+                         key=lambda x: x[:2])
+            stack = []
+            for a, negd, e in evs:
+                b = a - negd
+                while stack and stack[-1]["t1"] < b:
+                    stack.pop()
+                out.append(dict(name=e.name, t0=a, t1=b,
+                                stats=dict(e.stats),
+                                parent=stack[-1]["name"] if stack else None,
+                                id=len(out),
+                                pid=stack[-1]["id"] if stack else None))
+                stack.append(out[-1])
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled_run(smoke_model, tmp_path_factory):
+    """The end-to-end test's geometry under pressure (one placement
+    stream, longer requests, synchronous cleaning), drained while the JAX
+    profiler records, with a tracer and phase rows on."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.serving import PagedServingEngine
+    model, params = smoke_model
+    tracer = Tracer(capacity=1 << 16)
+    eng = PagedServingEngine(
+        model, n_slabs=8, blocks_per_slab=4, page_T=8, max_batch=3,
+        max_seq=96, policy="mdc", params=params, compact_trigger=2,
+        compact_batch=3, streams=1, pool_dtype=jnp.float32,
+        preemption=True, warmup=True, tracer=tracer, phase_log=True)
+    rng = np.random.default_rng(3)
+    for _ in range(10):
+        eng.submit(rng.integers(1, model.cfg.vocab_size,
+                                size=int(rng.integers(20, 60))),
+                   int(rng.integers(8, 30)))
+    m0 = eng.metrics()
+    tdir = tmp_path_factory.mktemp("profile")
+    jax.profiler.start_trace(str(tdir))
+    try:
+        while eng.has_work():
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    m1 = eng.metrics()
+    delta = {k: m1[k] - m0[k] for k in ("dispatches", "compactions",
+                                        "blocks_moved")}
+    return eng, tracer, _profiled_spans(tdir), delta
+
+
+# the innermost engine/pool span each span may sit in (None: outermost)
+PARENTS = {
+    "engine.step": {None},
+    "engine.admit": {"engine.step"},
+    "engine.prefill": {"engine.admit"},
+    "engine.alloc": {"engine.step", "engine.admit"},
+    "engine.upload": {"engine.step"},
+    "engine.dispatch": {"engine.step"},
+    "engine.host_sync": {"engine.step", "engine.admit", "engine.emit"},
+    "engine.emit": {"engine.step"},
+    "pool.compact": {"engine.alloc"},
+    "engine.compaction": {"pool.compact"},
+}
+
+
+def test_profiled_engine_spans_nest(profiled_run):
+    eng, _, spans, delta = profiled_run
+    assert delta["compactions"] > 0 and delta["blocks_moved"] > 0, \
+        "the profiled run never cleaned"
+    names = {s["name"] for s in spans}
+    assert names == set(PARENTS), names
+    for s in spans:
+        assert s["parent"] in PARENTS[s["name"]], s
+    # the step span carries its dispatch index
+    steps = [s for s in spans if s["name"] == "engine.step"]
+    idx = [s["stats"]["dispatch"] for s in steps]
+    assert idx == sorted(idx) and idx[-1] == eng.dispatches - 1
+
+
+def test_profiled_engine_span_counts_match_counters(profiled_run):
+    eng, _, spans, delta = profiled_run
+    kids: dict = {}
+    for s in spans:
+        kids.setdefault(s["pid"], []).append(s["name"])
+    steps = [s for s in spans if s["name"] == "engine.step"]
+    dispatching = [s for s in steps if "engine.dispatch" in kids.get(
+        s["id"], [])]
+    assert len(dispatching) == delta["dispatches"]
+    # every cycle gets a span; those that cleaned a slab are the
+    # compactions, and the rest found no victim and moved nothing
+    cycles = [s for s in spans if s["name"] == "pool.compact"]
+    cleaned = [s for s in cycles if s["stats"]["victims"] > 0]
+    assert len(cleaned) == delta["compactions"]
+    assert sum(s["stats"]["moves"] for s in cycles) == delta["blocks_moved"]
+    assert all(s["stats"]["moves"] == 0 for s in cycles
+               if s["stats"]["victims"] == 0)
+    # synchronous cleaning: the engine's move and remap run inside the
+    # cycle that planned them, once per cycle with moves
+    moved = [s for s in cycles if s["stats"]["moves"] > 0]
+    assert sorted(s["pid"] for s in spans
+                  if s["name"] == "engine.compaction") \
+        == sorted(s["id"] for s in moved)
+    # an admission that prefilled waited for the prefill's first token
+    admits = [s for s in spans if s["name"] == "engine.admit"]
+    prefilled = [a for a in admits if "engine.prefill" in kids.get(
+        a["id"], [])]
+    assert prefilled
+    for a in prefilled:
+        k = kids[a["id"]]
+        assert k.count("engine.host_sync") == k.count("engine.prefill")
+
+
+def test_profiled_engine_tracer_names_and_phase_keys(profiled_run):
+    """The tracer records the same spans under the profiler's names less
+    the category, and ``phase_report()`` splits dispatches into the same
+    phases as before the profiler spans."""
+    eng, tracer, spans, _ = profiled_run
+    begins = [e for e in tracer.events() if e["ph"] == "B"]
+    assert sorted(f"{e['cat']}.{e['name']}" for e in begins) \
+        == sorted(s["name"] for s in spans)
+    _check_chrome_trace(tracer.export())
+    assert {"step", "admit", "upload", "dispatch", "host_sync",
+            "compaction"} <= {e["name"] for e in begins}
+    pr = eng.phase_report()
+    assert set(pr) == {"dispatches", "p50_ms", "p99_ms", "phase_mean_ms",
+                       "phase_share_p99_tail", "compaction_share_p99",
+                       "compaction_share_total"}
+    assert {"admit", "upload", "dispatch", "host_sync", "compaction"} \
+        <= set(pr["phase_mean_ms"]) <= REPORT_PHASES
+    assert pr["dispatches"] == eng.dispatches
