@@ -54,6 +54,7 @@ from ..models import attention as att
 from ..models import transformer as tfm
 from ..models.layers import rmsnorm
 from .. import kernels
+from ..kernels.paged_attention import pages_fetched
 from ..core.logstructure import FENCED, JournalLog, Placement
 from ..distributed.fault import TransientFault, backoff_delay
 from ..obs import DeathCalibration, MetricsLogger, span
@@ -220,6 +221,13 @@ def _build_decode_step(cfg, page_T, use_pallas, max_chunk, mesh, kv_shard,
             # npages*page_T from indexing one past its table)
             page = jnp.where(active, page, trash_page)
         off = seq_lens % page_T
+        # each slot attends its seq_len + 1 tokens; on the kernel path an
+        # inactive slot, whose output is discarded, attends none, so the
+        # kernel fetches nothing for it (the jnp path reads every slot's
+        # whole table either way)
+        att_lens = seq_lens + 1
+        if use_pallas:
+            att_lens = jnp.where(active, att_lens, 0)
 
         def layer(carry, lp):
             h, kps, vps, li = carry
@@ -227,8 +235,8 @@ def _build_decode_step(cfg, page_T, use_pallas, max_chunk, mesh, kv_shard,
             q, k, v = att._project_qkv(hn, lp["attn"], cfg, pos)
             kps = _write_row(kps, li, page, off, k[:, 0])
             vps = _write_row(vps, li, page, off, v[:, 0])
-            o = _paged_attn(q[:, 0], kps, vps, li, bt, seq_lens + 1,
-                            use_pallas, mesh)
+            o = _paged_attn(q[:, 0], kps, vps, li, bt, att_lens, use_pallas,
+                            mesh)
             if rep_shard is not None:
                 # all-gather the (B, H, hd) context so the cross-head wo
                 # contraction runs in full on every shard (bit-identity)
@@ -543,6 +551,7 @@ class PagedServingEngine:
         # them; the decode dispatch itself keeps seq_lens/tokens on device) --
         self._bt_dev = self._put_rep(self.bt)
         self._lens_dev = self._put_rep(self.lens)
+        self._lens_on_dev = self.lens.copy()   # _lens_dev's values, on the host
         self._tok_dev = self._put_rep(self.tokens)
         self._act_dev = self._put_rep((self.rid >= 0) & ~self._prefilling)
         self._bt_dirty = False
@@ -622,6 +631,10 @@ class PagedServingEngine:
         self.fault_retries_done = 0   # transient faults cleared by retry
         self.fault_unwinds = 0        # admissions unwound by a fault
         self.dispatches = 0
+        # the paged-attention kernel's engagement, over every slot of every
+        # decode iteration: pages it fetched, and the B x P table it could
+        self.attn_pages_read = 0
+        self.attn_pages_table = 0
         self._admit_stalled = False
         self._tpot_ewma = 0.05        # s/token, seeds the retry-after hint
         self.recovery: dict | None = None   # set by recovery.recover_engine
@@ -1361,6 +1374,7 @@ class PagedServingEngine:
             self._bt_dirty = False
         if self._state_dirty:
             self._lens_dev = self._put_rep(self.lens)
+            self._lens_on_dev = self.lens.copy()
             self._tok_dev = self._put_rep(self.tokens)
             # a prefilling slot is NOT decode-active: the fused dispatch
             # writes its chunk K/V while decode skips it until the final
@@ -1560,6 +1574,8 @@ class PagedServingEngine:
             has = hit.any(axis=0)
             emitted[act[has]] = hit.argmax(axis=0)[has] + 1
             stopped[act[has]] = True
+        if self.use_pallas:
+            self._count_attn_pages(n, np.where(active, emitted, 0))
         for i in act:
             e = int(emitted[i])
             w = self._out_n[i]
@@ -1610,6 +1626,17 @@ class PagedServingEngine:
         if self.audit_every and self.dispatches % self.audit_every == 0:
             self.audit()
         return done
+
+    def _count_attn_pages(self, n: int, emitted: np.ndarray) -> None:
+        """Add one dispatch's ``n`` decode iterations to the kernel's page
+        counters.  A slot that emitted ``e`` tokens attended its device
+        length + 1 in iterations 0..e-1, growing by one each, and nothing
+        after it stopped; an inactive slot attends nothing."""
+        it = np.arange(n)[:, None]
+        lens = np.where(it < emitted, self._lens_on_dev + it + 1, 0)  # (n, B)
+        self.attn_pages_read += int(pages_fetched(lens, self.page_T).sum())
+        self.attn_pages_table += n * self.bt.size
+        self._lens_on_dev += emitted
 
     def run_to_completion(self, max_steps: int = 100_000) -> dict:
         for _ in range(max_steps):
@@ -1918,6 +1945,8 @@ class PagedServingEngine:
             "resumes": self.resumes,
             "recomputed_tokens": self.recomputed_tokens,
             "dispatches": self.dispatches,
+            "attn_pages_read": self.attn_pages_read,
+            "attn_pages_table": self.attn_pages_table,
         }
         if self.shed_queue_depth:
             m["shed_count"] = self.shed_count

@@ -71,30 +71,87 @@ def test_flash_attention_matches_model_chunked_path():
 
 # ------------------------------------------------------------ paged attention
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,Kh,D,T,P", [
-    (2, 4, 4, 64, 16, 4),
-    (3, 8, 2, 32, 8, 6),      # GQA 4:1
-    (1, 4, 1, 128, 32, 3),    # MQA
-])
-def test_paged_attention_matches_ref(B, H, Kh, D, T, P, dtype):
-    key = jax.random.PRNGKey(3)
-    ks = jax.random.split(key, 4)
+def _paged_case(B, H, Kh, D, T, P, L, dtype, seed=3):
+    """q, stacked (L, pages, Kh, T, D) pools and a block table of disjoint
+    random pages per sequence (as the slab allocator would give)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     n_pages = B * P + 5
     q = rand(ks[0], (B, H, D), dtype)
-    k_pool = rand(ks[1], (n_pages, Kh, T, D), dtype)
-    v_pool = rand(ks[2], (n_pages, Kh, T, D), dtype)
-    # each sequence gets disjoint random pages (as the slab allocator would)
+    k_pools = rand(ks[1], (L, n_pages, Kh, T, D), dtype)
+    v_pools = rand(ks[2], (L, n_pages, Kh, T, D), dtype)
     perm = jax.random.permutation(ks[3], n_pages)[: B * P]
-    block_tables = perm.reshape(B, P).astype(jnp.int32)
-    # ragged lengths incl. exactly-one-page and full
-    lens = np.linspace(1, P * T, B).astype(np.int32)
-    seq_lens = jnp.asarray(lens)
-    got = ops.paged_attention(q, k_pool[None], v_pool[None], block_tables,
-                              seq_lens, layer=0)
-    want = ref.paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens)
+    return q, k_pools, v_pools, perm.reshape(B, P).astype(jnp.int32)
+
+
+# The kernel walks blocks of pages_per_block(T, P) pages (512 tokens): at
+# T 32 a block is 16 pages, at T 16 it is 32.
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B,H,Kh,D,T,P,lens,L", [
+    pytest.param(2, 4, 4, 64, 16, 4, None, 1, id="2-4-4-64-16-4"),
+    # GQA 4:1
+    pytest.param(3, 8, 2, 32, 8, 6, None, 1, id="3-8-2-32-8-6"),
+    # MQA
+    pytest.param(1, 4, 1, 128, 32, 3, None, 1, id="1-4-1-128-32-3"),
+    # one token; mid-page; on a page boundary; on a block boundary
+    pytest.param(4, 4, 2, 64, 32, 20, (1, 45, 64, 512), 1, id="edges"),
+    # a full table whose width (20) is no multiple of the block (16 pages)
+    pytest.param(2, 4, 2, 64, 32, 20, (640, 600), 1, id="full-table"),
+    # a stacked pool of 3 layers, read at layer 2
+    pytest.param(3, 8, 2, 32, 16, 6, None, 3, id="layer-2-of-3"),
+    # GQA 8:1 over two blocks
+    pytest.param(2, 16, 2, 128, 16, 40, (600, 17), 1, id="gqa-8"),
+])
+def test_paged_attention_matches_ref(B, H, Kh, D, T, P, lens, L, dtype):
+    q, k_pools, v_pools, block_tables = _paged_case(B, H, Kh, D, T, P, L,
+                                                    dtype)
+    if lens is None:     # ragged lengths incl. exactly-one-page and full
+        lens = np.linspace(1, P * T, B).astype(np.int32)
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    got = ops.paged_attention(q, k_pools, v_pools, block_tables, seq_lens,
+                              layer=L - 1)
+    want = ref.paged_attention_ref(q, k_pools[L - 1], v_pools[L - 1],
+                                   block_tables, seq_lens)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
+
+
+@pytest.mark.parametrize("interpret", ["interpret", "interpret_params"])
+def test_paged_attention_never_reads_dead_pages(interpret):
+    """Pages past each sequence's live length hold NaN in the pool, and so
+    do the rows past the length in its last page; the table's dead entries
+    name pages past the pool's end.  The kernel must neither fetch nor mix
+    them: finite output, equal to the reference over the same pools with
+    the NaNs zeroed.  Under ``InterpretParams`` unfetched VMEM is NaN too,
+    and a read past the block table or the pool raises."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.paged_attention import paged_attention_bkgd
+    B, H, Kh, D, T, P, L = 6, 8, 2, 64, 32, 20, 2
+    q, k_pools, v_pools, bt = _paged_case(B, H, Kh, D, T, P, L, jnp.float32)
+    # empty sequences first, between and last: nothing to fetch or prefetch
+    lens = np.array([0, 1, 0, 45, 600, 0], np.int32)
+    k, v, bt_np = np.array(k_pools), np.array(v_pools), np.asarray(bt)
+    for b, n in enumerate(lens):
+        last = (n - 1) // T
+        for pool in (k, v):
+            pool[:, bt_np[b, last + 1:]] = np.nan
+            pool[:, bt_np[b, last], :, n - last * T:] = np.nan
+    dead = np.arange(P)[None] > (lens[:, None] - 1) // T
+    bt_dead = np.where(dead, k.shape[1] + np.arange(P)[None], bt_np)
+    mode = True if interpret == "interpret" else pltpu.InterpretParams()
+    got = paged_attention_bkgd(q.reshape(B, Kh, H // Kh, D), jnp.asarray(k),
+                               jnp.asarray(v), jnp.asarray(bt_dead, jnp.int32),
+                               jnp.asarray(lens), jnp.int32(1),
+                               interpret=mode)
+    got = np.asarray(got).reshape(B, H, D)
+    assert np.isfinite(got).all()
+    live = lens > 0
+    assert (got[~live] == 0).all()                 # nothing attended
+    want = ref.paged_attention_ref(q, jnp.asarray(np.nan_to_num(k[1])),
+                                   jnp.asarray(np.nan_to_num(v[1])), bt,
+                                   jnp.asarray(lens))
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               **TOL[jnp.float32])
 
 
 def test_paged_attention_equals_dense_decode():

@@ -394,6 +394,47 @@ def test_stop_token_early_exit_matches_dense(smoke_model, use_pallas):
     assert eng.metrics()["free_blocks"] == eng.pool.n_slabs * eng.pool.S
 
 
+def test_attn_page_counters_match_kernel_inputs(smoke_model, monkeypatch):
+    """``attn_pages_read`` / ``attn_pages_table`` equal the pages and table
+    entries of every slot of every decode iteration, summed from the
+    lengths the paged-attention kernel is actually handed (a callback on
+    its inputs at layer 0), under multi-token dispatches with a stop token
+    that freezes slots mid-dispatch.  Inactive slots attend nothing."""
+    import jax
+
+    from repro.kernels import ops
+    seen = []
+    real = ops.paged_attention_bkgd
+
+    def record(lens, layer):
+        if int(layer) == 0:
+            seen.append(np.asarray(lens))
+
+    def spy(q, k, v, bt, lens, layer, **kw):
+        jax.debug.callback(record, lens, layer)
+        return real(q, k, v, bt, lens, layer, **kw)
+
+    monkeypatch.setattr(ops, "paged_attention_bkgd", spy)
+    T, P, B = 8, 12, 3
+    eng = PagedServingEngine(smoke_model, n_slabs=14, blocks_per_slab=2,
+                             page_T=T, max_batch=B, max_seq=P * T,
+                             policy="mdc", compact_trigger=2,
+                             compact_batch=3, seed=0, use_pallas=True,
+                             max_decode_chunk=8, stop_token=335,
+                             admit_every_dispatch=False)
+    rids, news = _mixed_stream(eng, smoke_model.cfg.vocab_size)
+    eng.run_to_completion()
+    assert any(len(eng.finished[r]) < n for r, n in zip(rids, news))
+    jax.effects_barrier()
+    m = eng.metrics()
+    assert len(seen) > eng.dispatches           # multi-token dispatches
+    assert m["attn_pages_read"] == sum(
+        int(np.ceil(s / T).sum()) for s in seen)
+    assert m["attn_pages_table"] == len(seen) * B * P
+    assert 0 < m["attn_pages_read"] < m["attn_pages_table"]
+    assert any((s == 0).any() for s in seen)     # an idle slot reads nothing
+
+
 def test_stop_token_chunked_equals_singlestep(smoke_model):
     """Mid-dispatch stops must be invisible to the tokens: a multi-token
     dispatch engine truncates exactly where the single-token engine does.

@@ -54,14 +54,22 @@ def _compile(fn, *shapes):
     return compiled
 
 
-def test_paged_attention_compiles(one_chip):
+# (layers, query heads, kv heads, head dim) of the benchmark's
+# configurations: qwen3-1.7b (G = 2) and the 8-layer Qwen3-32B stage (G = 8)
+PAGED_SHAPES = {"qwen3-1.7b": (CFG.n_layers, H, KH, D),
+                "qwen3-32b-8l": (8, 64, 8, 128)}
+
+
+@pytest.mark.parametrize("name", sorted(PAGED_SHAPES))
+def test_paged_attention_compiles(one_chip, name):
     from repro.kernels.paged_attention import paged_attention_bkgd
 
+    L, h, kh, d = PAGED_SHAPES[name]
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-    pool = sds((CFG.n_layers, N_PAGES, KH, T, D), jnp.bfloat16)
+    pool = sds((L, N_PAGES, kh, T, d), jnp.bfloat16)
     _compile(lambda q, k, v, bt, sl, li: paged_attention_bkgd(
                  q, k, v, bt, sl, li, interpret=False),
-             sds((B, KH, H // KH, D), jnp.bfloat16), pool, pool,
+             sds((B, kh, h // kh, d), jnp.bfloat16), pool, pool,
              sds((B, MAX_SEQ // T), jnp.int32), sds((B,), jnp.int32),
              sds((), jnp.int32))
 
